@@ -48,6 +48,7 @@ import tempfile
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import CostModelConfig, GNNConfig, init_cost_model
 from repro.dsps import WorkloadGenerator
 from repro.launch.faults import straggler_outliers
@@ -287,6 +288,7 @@ def run(
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--structures", type=int, default=8)
     ap.add_argument("--requests", type=int, default=120)

@@ -44,6 +44,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.control import (
     FleetRuntime,
     PlacementController,
@@ -121,6 +122,7 @@ def run(n_queries: int, n_ticks: int, seed: int = 7) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--queries", type=int, default=8)
     ap.add_argument("--ticks", type=int, default=30)

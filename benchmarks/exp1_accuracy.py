@@ -16,6 +16,7 @@ from benchmarks.common import (
     save_result,
     test_split_traces,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import ALL_METRICS, REGRESSION_METRICS
 from repro.dsps.query import OpType
 
@@ -115,6 +116,7 @@ def fig8_query_types():
 
 
 def main():
+    enable_compile_cache()
     table3()
     fig7_hardware_buckets()
     fig8_query_types()

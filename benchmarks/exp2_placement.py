@@ -13,6 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import FlatRanker, fmt_table, save_result, serving_estimator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.dsps import WorkloadGenerator, simulate
 from repro.dsps.simulator import SimulatorConfig
 from repro.placement import (
@@ -99,6 +100,7 @@ def exp2b(n_queries: int = 25, seed: int = 4321):
 
 
 def main():
+    enable_compile_cache()
     exp2a()
     exp2b()
 

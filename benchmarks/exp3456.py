@@ -14,6 +14,7 @@ from typing import Dict, List
 import numpy as np
 
 from benchmarks.common import eval_costream, eval_flat, fmt_table, save_result
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import ALL_METRICS, REGRESSION_METRICS
 from repro.dsps import ranges
 from repro.dsps.generator import GeneratorConfig, Trace, WorkloadGenerator
@@ -183,6 +184,7 @@ def exp6_unseen_benchmarks(n: int = 100):
 
 
 def main():
+    enable_compile_cache()
     exp3_interpolation()
     exp4_extrapolation()
     exp5_unseen_patterns()

@@ -9,6 +9,7 @@ scheme; regression q-errors.
 from __future__ import annotations
 
 from benchmarks.common import eval_costream, fmt_table, save_result, test_split_traces
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import REGRESSION_METRICS
 from repro.core.graph import drop_hardware, drop_hw_features
 
@@ -60,6 +61,7 @@ def exp7b():
 
 
 def main():
+    enable_compile_cache()
     exp7a()
     exp7b()
 

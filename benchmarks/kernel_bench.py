@@ -40,6 +40,7 @@ except ModuleNotFoundError:  # invoked as a script (scripts/ci.sh): repo root of
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from benchmarks.common import save_result
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.bucketing import batch_banding, bucket_size, exact_banding, pad_batch
 from repro.core.gnn import GNNConfig, _banded_plan, apply_gnn_merged, init_gnn
 from repro.core.graph import SLOT_RANGES, batch_graphs, build_a_place_batch, build_graph_skeleton
@@ -173,6 +174,7 @@ def run(n_traces: int, hidden: int, repeats: int) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--traces", type=int, default=48)
     ap.add_argument("--hidden", type=int, default=64)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import save_result
+from repro.launch.compile_cache import enable_compile_cache
 from repro import nn
 from repro.core.graph import SLOT_RANGES
 from repro.kernels.banked_mlp.ops import banked_mlp_slotted
@@ -37,6 +38,7 @@ def _time(fn, *args, iters=10):
 
 
 def main():
+    enable_compile_cache()
     rows = []
     # banked MLP
     p = nn.init_mlp_bank(jax.random.PRNGKey(0), 5, [39, 64, 64])

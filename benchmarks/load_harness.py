@@ -43,6 +43,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import CostModelConfig, GNNConfig, init_cost_model
 from repro.dsps import WorkloadGenerator
 from repro.serve import (
@@ -236,6 +237,7 @@ def run(
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--structures", type=int, default=16)
     ap.add_argument("--requests", type=int, default=192)

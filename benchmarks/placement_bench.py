@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 import repro.core.graph as graph_mod
 import repro.placement.optimizer as optimizer_mod
 import repro.serve.estimator as estimator_mod
@@ -211,6 +212,7 @@ def run(n_candidates: int, repeats: int, seed: int = 0) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--candidates", type=int, default=1024)
     ap.add_argument("--repeats", type=int, default=3)

@@ -7,6 +7,7 @@ import json
 import os
 
 from benchmarks.common import fmt_table, save_result
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import artifacts
 
 
@@ -19,6 +20,7 @@ def load_cells(mesh: str = "single", tag: str = ""):
 
 
 def main(mesh: str = "single"):
+    enable_compile_cache()
     cells = load_cells(mesh)
     rows = []
     for c in cells:

@@ -12,8 +12,11 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="all")
     args = ap.parse_args()

@@ -42,6 +42,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import CostModelConfig, GNNConfig, init_cost_model
 from repro.core.bucketing import bucket_size
 from repro.dsps import WorkloadGenerator
@@ -307,6 +308,7 @@ def run_estimate(n_requests: int, graphs_per_request: int, repeats: int, seed: i
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mode", choices=("score", "mixed", "estimate"), default="score")
     ap.add_argument("--requests", type=int, default=None)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro import nn
 from repro.core import CostModelConfig, GNNConfig, init_cost_model
 from repro.core.graph import SLOT_RANGES
@@ -197,6 +198,7 @@ def run(n_traces: int, batch_size: int, repeats: int, seed: int = 0) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--traces", type=int, default=2048)
     ap.add_argument("--batch-size", type=int, default=256)

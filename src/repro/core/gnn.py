@@ -30,9 +30,10 @@ the stage-3 data-flow sweep runs —
   level are recomputed).
 
 ``GNNConfig.use_pallas`` routes every plan kind through ``kernels/banked_mlp``
-(stages 0-2) and ``kernels/mp_sweep`` / ``kernels/mp_update`` (stage 3), and
-the cross-query merged engine through ``kernels/seg_gather``; configs the
-kernels cannot fuse raise loudly instead of silently falling back.
+(stages 0-2) and ``kernels/mp_sweep`` / ``kernels/mp_update`` (stage 3);
+configs the kernels cannot fuse raise loudly instead of silently falling
+back.  The cross-query merged engine calls ``kernels/seg_gather`` whatever
+``use_pallas`` says, so on the TPU it always runs those Pallas kernels.
 
 ``apply_gnn_traditional`` is the Exp-7b ablation: K rounds of symmetric
 neighbor aggregation with shared (non-type-specific ordering) updates.

@@ -19,7 +19,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.training import optim
@@ -63,12 +62,12 @@ def make_dp_train_step(
         params = optim.apply_updates(params, updates)
         return params, opt_state, loss
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step_shard,
         mesh=mesh,
         in_specs=(P(), P(), bspec, P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

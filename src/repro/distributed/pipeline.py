@@ -15,7 +15,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,10 +70,10 @@ def pipeline_forward(
         ) if n_stages > 1 else outs
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(pipe_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

@@ -55,7 +55,8 @@ def banked_mlp_slotted_pallas(
     x: jax.Array,
     slot_ranges: Sequence[Tuple[int, int, int]],
     tile_b: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """x: (B, N, F) -> (B, N, H2)."""
     l1, l2 = params["layers"]

@@ -63,8 +63,8 @@ def _kernel(h_ref, a_ref, depth_ref, mask_ref, w1_ref, b1_ref, w2_ref, b2_ref, o
         upd = jnp.concatenate(outs, axis=1)
         # 4. depth select inside the span; the state value (not HBM) carries
         #    the update into the next level's aggregation
-        sel = (depth_ref[:, s:e] == d) & (mask_ref[:, s:e] > 0)
-        new = jnp.where(sel[..., None], upd, h[:, s:e]).astype(h.dtype)
+        sel = (depth_ref[:, s:e, :] == d) & (mask_ref[:, s:e, :] > 0)  # (TB, e-s, 1)
+        new = jnp.where(sel, upd, h[:, s:e]).astype(h.dtype)
         pieces = ([h[:, :s]] if s else []) + [new] + ([h[:, e:]] if e < n else [])
         h = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
     out_ref[...] = h.astype(out_ref.dtype)
@@ -78,7 +78,8 @@ def mp_sweep_pallas(
     mask: jax.Array,  # (B, N) float32
     levels,  # ((d, (s, e), slot_ranges, parent_rows | None), ...) static
     tile_b: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """One ``pallas_call`` for the whole banded sweep; ``levels`` are the
     banding's per-level constants (``gnn.StagePlan("sweep").levels``): depth
@@ -110,8 +111,8 @@ def mp_sweep_pallas(
         in_specs=[
             pl.BlockSpec((tb, N, H), lambda i: (i, 0, 0)),
             pl.BlockSpec((tb, N, N), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, N), lambda i: (i, 0)),
-            pl.BlockSpec((tb, N), lambda i: (i, 0)),
+            pl.BlockSpec((tb, N, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, N, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec(w1.shape, lambda i: (0, 0, 0)),
             pl.BlockSpec(b1.shape, lambda i: (0, 0)),
             pl.BlockSpec(w2.shape, lambda i: (0, 0, 0)),
@@ -120,4 +121,4 @@ def mp_sweep_pallas(
         out_specs=pl.BlockSpec((tb, N, H), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, H), h.dtype),
         interpret=interpret,
-    )(h, a_flow, depth, mask, w1, b1, w2, b2)
+    )(h, a_flow, depth[..., None], mask[..., None], w1, b1, w2, b2)

@@ -56,9 +56,9 @@ def _kernel(h_ref, a_ref, depth_ref, mask_ref, d_ref, w1_ref, b1_ref, w2_ref, b2
     upd = jnp.concatenate(outs, axis=1)
     # 4. depth select inside the span; rows outside pass through untouched
     d = d_ref[0]
-    sel = (depth_ref[:, s:e] == d) & (mask_ref[:, s:e] > 0)
+    sel = (depth_ref[:, s:e, :] == d) & (mask_ref[:, s:e, :] > 0)  # (TB, e-s, 1)
     out_ref[...] = h.astype(out_ref.dtype)
-    out_ref[:, s:e, :] = jnp.where(sel[..., None], upd, h[:, s:e]).astype(out_ref.dtype)
+    out_ref[:, s:e, :] = jnp.where(sel, upd, h[:, s:e]).astype(out_ref.dtype)
 
 
 def mp_update_pallas(
@@ -70,7 +70,8 @@ def mp_update_pallas(
     d: jax.Array,  # () int32
     slot_ranges: Sequence[Tuple[int, int, int]],
     tile_b: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     row_span: Tuple[int, int] = None,
     parent_rows: int = None,
 ) -> jax.Array:
@@ -110,8 +111,8 @@ def mp_update_pallas(
         in_specs=[
             pl.BlockSpec((tb, N, H), lambda i: (i, 0, 0)),
             pl.BlockSpec((tb, N, N), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, N), lambda i: (i, 0)),
-            pl.BlockSpec((tb, N), lambda i: (i, 0)),
+            pl.BlockSpec((tb, N, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, N, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1,), lambda i: (0,)),
             pl.BlockSpec(w1.shape, lambda i: (0, 0, 0)),
             pl.BlockSpec(b1.shape, lambda i: (0, 0)),
@@ -121,4 +122,4 @@ def mp_update_pallas(
         out_specs=pl.BlockSpec((tb, N, H), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, H), h.dtype),
         interpret=interpret,
-    )(h, a_flow, depth, mask, d_arr, w1, b1, w2, b2)
+    )(h, a_flow, depth[..., None], mask[..., None], d_arr, w1, b1, w2, b2)

@@ -58,7 +58,8 @@ def linear_scan_pallas(
     h0: jax.Array,
     tile_b: int = 4,
     tile_t: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, T, D = a.shape
     tb, tt = min(tile_b, B), min(tile_t, T)

@@ -65,7 +65,8 @@ def gather_sum_pallas(
     idx: jax.Array,  # (B, R, P) int
     w: jax.Array,  # (B, R, P)
     tile_b: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, N, H = h.shape
     _, R, P = idx.shape
@@ -96,7 +97,8 @@ def segment_sum_pallas(
     seg: jax.Array,  # (B, N) int
     n_seg: int,
     tile_b: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, N, H = x.shape
     tb = min(tile_b, B)

@@ -36,6 +36,7 @@ from repro.core.model import (
 from repro.dsps import ranges
 from repro.dsps.generator import GeneratorConfig, WorkloadGenerator
 from repro.launch import artifacts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.batching import dataset_from_traces, split_dataset, split_indices
 from repro.training.loop import TrainConfig, train_cost_model, train_flat_model
 
@@ -293,6 +294,7 @@ def stage_finetune(epochs: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--stage", default="all", choices=["all", "main", "flat", "extrap", "ablations", "finetune"])
     ap.add_argument("--epochs", type=int, default=26)

@@ -295,18 +295,31 @@ def prefetch(it: Iterator, size: int = 2) -> Iterator:
     """Background-thread prefetch (overlaps host prep with device compute)."""
     q: queue.Queue = queue.Queue(maxsize=size)
     stop = object()
+    closed = threading.Event()
 
     def worker():
         try:
             for item in it:
+                if closed.is_set():
+                    return
                 q.put(item)
         finally:
             q.put(stop)
 
     t = threading.Thread(target=worker, daemon=True)
     t.start()
-    while True:
-        item = q.get()
-        if item is stop:
-            return
-        yield item
+    try:
+        while True:
+            item = q.get()
+            if item is stop:
+                return
+            yield item
+    finally:
+        # a consumer that stops early (``TrainConfig.max_steps``) must not
+        # leave the worker blocked on a full queue: drain until it exits
+        closed.set()
+        while t.is_alive():
+            try:
+                q.get(timeout=0.1)
+            except queue.Empty:
+                pass

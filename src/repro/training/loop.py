@@ -68,6 +68,8 @@ class TrainConfig:
     # signature instead of per depth class) — worth it for large fixed
     # corpora where every signature class dwarfs a batch (launch/train.py)
     exact_banding: bool = False
+    # cap on optimizer steps over the whole run (None: every epoch runs)
+    max_steps: Optional[int] = None
     topk_frac: float = 0.05
     early_stop_patience: int = 6
     log_every: int = 50
@@ -107,6 +109,8 @@ def train_cost_model(
     dataset_train, buckets = bucket_dataset(dataset_train, exact=train_cfg.exact_banding)
     steps_per_epoch = max(1, n_batches(buckets, train_cfg.batch_size))
     total = steps_per_epoch * train_cfg.epochs
+    if train_cfg.max_steps is not None:
+        total = min(total, train_cfg.max_steps)
     opt = optim.adam(
         lr=optim.cosine_schedule(train_cfg.lr, total, warmup_steps=min(100, total // 10)),
         weight_decay=train_cfg.weight_decay,
@@ -171,6 +175,8 @@ def train_cost_model(
             step += 1
             if train_cfg.ckpt_dir and step % train_cfg.ckpt_every == 0:
                 save_checkpoint(train_cfg.ckpt_dir, step, (params, opt_state, ef))
+            if train_cfg.max_steps is not None and step >= train_cfg.max_steps:
+                break
         vl = (
             float(val_loss_fn(params, val_g, val_y, val_banding))
             if len(dataset_val)
@@ -189,6 +195,7 @@ def train_cost_model(
                 f"[{model_cfg.metric}] epoch {epoch} train {history[-1]['train_loss']:.4f} "
                 f"val {vl:.4f} ({history[-1]['seconds']:.1f}s)"
             )
+        out_of_steps = train_cfg.max_steps is not None and step >= train_cfg.max_steps
         if vl < best_val - 1e-4:
             best_val = vl
             # snapshot to host numpy: live device buffers would be deleted by
@@ -199,6 +206,8 @@ def train_cost_model(
             bad_epochs += 1
             if bad_epochs >= train_cfg.early_stop_patience:
                 break
+        if out_of_steps:
+            break
 
     if train_cfg.ckpt_dir:
         save_checkpoint(train_cfg.ckpt_dir, step, (best_params, opt_state, ef))

@@ -262,6 +262,22 @@ def test_autotune_budget_zero_writes_default_profile_and_reuses(tmp_path):
     assert not res3.reused_cached
 
 
+def test_kernel_tile_probe_fails_with_the_compiler_message(monkeypatch):
+    """A kernel lowering that cannot compile stops the tile probe with the
+    compiler's error; no timing is recorded for it.  Off the TPU the Pallas
+    lowering (``interpret=False``) is such a kernel."""
+    import repro.kernels as kernels
+    from repro.kernels.mp_sweep import ops as sweep_ops
+    from repro.kernels.seg_gather import ops as seg_ops
+    from repro.serve.policy import _measure_kernel_tiles
+
+    for mod in (kernels, sweep_ops, seg_ops):
+        name = "active_lowering" if mod is kernels else "_lowering"
+        monkeypatch.setattr(mod, name, lambda: "pallas")
+    with pytest.raises(ValueError, match="interpret mode"):
+        _measure_kernel_tiles((32,), repeats=1, seed=0)
+
+
 def test_autotune_cli_validate_and_expect_cached(tmp_path, capsys):
     from repro.serve.policy import main
 

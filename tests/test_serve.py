@@ -537,11 +537,20 @@ def test_service_cross_query_off_restores_per_structure_drain():
     svc.start()
     got = [f.result(timeout=60) for f in futs]
     svc.close()
-    refs = [est.score(q1, c1, r) for r in reqs1] + [est.score(q2, c2, r) for r in reqs2]
+    # the drain scores each structure's requests as ONE concatenated
+    # candidate matrix (one bucket), so the reference is the facade call on
+    # exactly that batch, split back per request
+    refs = []
+    for q, c, reqs in ((q1, c1, reqs1), (q2, c2, reqs2)):
+        joined = est.score(q, c, np.concatenate(reqs))
+        off = 0
+        for r in reqs:
+            refs.append({m: v[off : off + len(r)] for m, v in joined.items()})
+            off += len(r)
     for want, have in zip(refs, got):
         for m in want:
             # per-structure groups take the same placement-specialized path
-            # as the direct facade call: answers are bit-identical
+            # on the same batch as the direct facade call: bit-identical
             np.testing.assert_array_equal(have[m], want[m], err_msg=m)
     assert svc.stats.n_forwards == 2  # one per structure
     assert svc.stats.n_cross_query == 0

@@ -333,6 +333,30 @@ def test_prefetch_order():
     assert list(prefetch(iter(range(10)), size=2)) == list(range(10))
 
 
+def test_prefetch_closed_early_stops_its_worker():
+    """A consumer that stops before the end (``max_steps``) must not leave
+    the worker thread blocked on the full queue."""
+    import threading
+
+    before = threading.active_count()
+    it = prefetch(iter(range(100)), size=2)
+    assert next(it) == 0
+    it.close()
+    assert threading.active_count() == before
+
+
+def test_train_max_steps_caps_the_run():
+    from repro.core import CostModelConfig, GNNConfig
+    from repro.training import TrainConfig, train_cost_model
+
+    ds = dataset_from_traces(WorkloadGenerator(seed=9).corpus(64), "latency_p")
+    tr, va, _ = split_dataset(ds, seed=0)
+    cfg = CostModelConfig(metric="latency_p", n_ensemble=2, gnn=GNNConfig(hidden=8))
+    res = train_cost_model(tr, va, cfg, TrainConfig(epochs=5, batch_size=8, max_steps=3))
+    assert res.steps == 3 and len(res.history) == 1
+    assert np.isfinite(res.history[0]["train_loss"])
+
+
 def test_elastic_shapes():
     assert shrink_mesh_shape((2, 16, 16), ("pod", "data", "model"), "data", 2) == (2, 8, 16)
     with pytest.raises(AssertionError):
