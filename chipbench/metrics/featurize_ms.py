@@ -1,0 +1,9 @@
+"""Estimator featurization: seconds in ``costream.featurize`` (placement
+adjacencies, bucket padding, the host merge) inside the traced sub-window,
+per request the service popped in it, in ms."""
+
+from chipbench.spans import per_request_ms
+
+
+def read(ctx):
+    return per_request_ms(ctx, ["costream.featurize"])

@@ -130,7 +130,10 @@ def _can_donate() -> bool:
 
 @_policy_lru
 def _jitted_forward(cfg: CostModelConfig, lowering: str = "ref"):
-    return jax.jit(lambda p, g: forward_ensemble(p, g, cfg))
+    def forward(p, g):
+        return forward_ensemble(p, g, cfg)
+
+    return jax.jit(forward)
 
 
 @_policy_lru
@@ -148,20 +151,21 @@ def _jitted_forward_stacked(
     # only callers that built the batch themselves for this one call may pass
     # it (the merged drain path); ``estimate`` takes caller-owned batches.
     cfg = CostModelConfig(metric="latency_p", gnn=gnn, traditional_mp=traditional_mp)
-    return jax.jit(
-        lambda p, g: forward_ensemble(p, g, cfg, banding),
-        donate_argnums=(1,) if donate else (),
-    )
+
+    def forward_stacked(p, g):
+        return forward_ensemble(p, g, cfg, banding)
+
+    return jax.jit(forward_stacked, donate_argnums=(1,) if donate else ())
 
 
 @_policy_lru
 def _jitted_placed_forward(cfg: CostModelConfig, static: QueryStatic, lowering: str = "ref"):
-    def f(p, skel, a_place):
+    def placed_forward(p, skel, a_place):
         return jax.vmap(
             lambda pp: apply_gnn_placed(pp, skel, a_place, static, cfg.gnn)[..., 0]
         )(p)
 
-    return jax.jit(f)
+    return jax.jit(placed_forward)
 
 
 @_policy_lru
@@ -177,10 +181,10 @@ def _jitted_placed_forward_stacked(
     # it selects is part of the trace, exactly like a shape.  ``donate``
     # releases ``a_place`` (per-drain, caller-built) — never the skeleton,
     # which lives in the estimator's LRU across calls.
-    def f(p, skel, a_place):
+    def placed_forward_stacked(p, skel, a_place):
         return apply_gnn_placed_stacked(p, skel, a_place, static, gnn, n_hw, chunk)
 
-    return jax.jit(f, donate_argnums=(2,) if donate else ())
+    return jax.jit(placed_forward_stacked, donate_argnums=(2,) if donate else ())
 
 
 @_policy_lru
@@ -195,10 +199,10 @@ def _jitted_merged_forward(
     # a_place); banding is the drain's signature-exact static plan.
     # ``donate`` releases the per-drain (skel_id, a_place) buffers — never
     # ``skels``, the cached device-resident skeleton stack of the mix.
-    def f(p, skels, skel_id, a_place):
+    def merged_forward(p, skels, skel_id, a_place):
         return apply_gnn_merged(p, skels, skel_id, a_place, gnn, banding, max_parents)
 
-    return jax.jit(f, donate_argnums=(2, 3) if donate else ())
+    return jax.jit(merged_forward, donate_argnums=(2, 3) if donate else ())
 
 
 class NonFiniteEstimate(RuntimeError):
@@ -263,6 +267,12 @@ class DeferredResult:
 
 def _maybe_defer(finalize, deferred: bool):
     return DeferredResult(finalize) if deferred else finalize()
+
+
+def _fetch(raw) -> np.ndarray:
+    """A forward's output on the host: blocks until the device is done."""
+    with jax.profiler.TraceAnnotation("costream.fetch"):
+        return np.asarray(raw)
 
 
 # -- stateless scoring primitives -------------------------------------------------
@@ -334,7 +344,7 @@ def placed_predict_fused(
         donate and _can_donate(),
     )
     raw = fwd(stacked.params, skel, a_place)
-    return _maybe_defer(lambda: _split_votes(np.asarray(raw), stacked), deferred)
+    return _maybe_defer(lambda: _split_votes(_fetch(raw), stacked), deferred)
 
 
 # -- the facade -------------------------------------------------------------------
@@ -464,13 +474,19 @@ class CostEstimator:
     # -- generic batch estimation -------------------------------------------------
 
     @staticmethod
+    def _host_graphs(batch) -> JointGraph:
+        """A batched ``JointGraph`` as given, or a sequence of traces
+        featurized into one on the host."""
+        if isinstance(batch, JointGraph):
+            return batch
+        with jax.profiler.TraceAnnotation("costream.featurize", rows=len(batch)):
+            return batch_graphs([build_graph(t.query, t.cluster, t.placement) for t in batch])
+
+    @staticmethod
     def _as_graphs(batch) -> JointGraph:
-        """A batched ``JointGraph``, or a sequence of traces to featurize."""
-        if not isinstance(batch, JointGraph):
-            batch = batch_graphs(
-                [build_graph(t.query, t.cluster, t.placement) for t in batch]
-            )
-        return jax.tree_util.tree_map(jnp.asarray, batch)
+        """A batched ``JointGraph``, or a sequence of traces to featurize,
+        on the device."""
+        return jax.tree_util.tree_map(jnp.asarray, CostEstimator._host_graphs(batch))
 
     def estimate(
         self, batch, metrics: Optional[Sequence[str]] = None, deferred: bool = False
@@ -488,30 +504,34 @@ class CostEstimator:
         a ``DeferredResult`` resolving to that dict once device work is done).
         """
         metrics = tuple(metrics) if metrics is not None else tuple(self.models)
-        g = self._as_graphs(batch)
-        self._before("estimate", int(g.op_x.shape[0]) if g.op_x.ndim == 3 else 1)
+        host = self._host_graphs(batch)
+        rows = int(host.op_x.shape[0]) if host.op_x.ndim == 3 else 1
+        self._before("estimate", rows)
         stacked = self._stacked_for(metrics)
-        if stacked is None:  # mixed architectures: per-metric forwards, shared batch
-            lowering = active_lowering()
-            raws = {
-                m: _jitted_forward(self.models[m][1], lowering)(self.models[m][0], g)
-                for m in metrics
-            }
-            return self._finish(
-                "estimate",
-                lambda: {
-                    m: _ensemble_vote(np.asarray(raws[m]), self.models[m][1])
+        with jax.profiler.TraceAnnotation("costream.dispatch", rows=rows):
+            g = jax.tree_util.tree_map(jnp.asarray, host)
+            if stacked is None:  # mixed architectures: per-metric forwards, shared batch
+                lowering = active_lowering()
+                raws = {
+                    m: _jitted_forward(self.models[m][1], lowering)(self.models[m][0], g)
                     for m in metrics
-                },
-                deferred,
-            )
-        fwd = _jitted_forward_stacked(
-            stacked.cfgs[0].gnn, stacked.cfgs[0].traditional_mp, None, active_lowering()
-        )
-        raw = fwd(stacked.params, g)
-        return self._finish(
-            "estimate", lambda: _split_votes(np.asarray(raw), stacked), deferred
-        )
+                }
+
+                def finalize():
+                    return {
+                        m: _ensemble_vote(_fetch(raws[m]), self.models[m][1]) for m in metrics
+                    }
+
+            else:
+                fwd = _jitted_forward_stacked(
+                    stacked.cfgs[0].gnn, stacked.cfgs[0].traditional_mp, None, active_lowering()
+                )
+                raw = fwd(stacked.params, g)
+
+                def finalize():
+                    return _split_votes(_fetch(raw), stacked)
+
+        return self._finish("estimate", finalize, deferred)
 
     def proba(self, batch, metric: str) -> np.ndarray:
         """Mean ensemble probability for one classification metric."""
@@ -578,9 +598,10 @@ class CostEstimator:
                 n = len(assignments)
                 if n == 0:  # not assert: callers (the service) rely on it under -O
                     raise ValueError("no candidates to score")
-                graphs = pad_batch(
-                    build_graph_batch(query, cluster, assignments), bucket_size(n)
-                )
+                with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
+                    graphs = pad_batch(
+                        build_graph_batch(query, cluster, assignments), bucket_size(n)
+                    )
                 # hooks + the finiteness guard fire inside the delegated
                 # ``estimate`` (kind "estimate"), not a second time here
                 pending = self.estimate(graphs, metrics, deferred=True)
@@ -598,31 +619,37 @@ class CostEstimator:
             if n == 0:  # not assert: callers (the service) rely on it under -O
                 raise ValueError("no candidates to score")
             self._before("score", n)
-            a_place = build_a_place_batch(query, cluster, assignments)
-            pad = bucket_size(n) - n
-            if pad:
-                a_place = np.concatenate([a_place, np.repeat(a_place[-1:], pad, axis=0)])
-            a_place = jnp.asarray(a_place)
-            if stacked is not None:
-                # a_place was built above for this one call: donate its buffer
-                pending = placed_predict_fused(
-                    stacked, skel, a_place, static, deferred=True,
-                    chunk=self.policy.score_chunk, donate=True,
-                )
-                return self._finish(
-                    "score",
-                    lambda: {m: v[:n] for m, v in pending.result().items()},
-                    deferred,
-                )
-            # heterogeneous (non-fusable) configs: per-metric loop, computed
-            # eagerly — the rare path keeps no deferral, only the wrapper type
-            out = {
-                m: placed_predict(
-                    self.models[m][0], skel, a_place, static, self.models[m][1]
-                )[:n]
-                for m in metrics
-            }
-            return self._finish("score", lambda: out, deferred)
+            with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
+                a_place = build_a_place_batch(query, cluster, assignments)
+                pad = bucket_size(n) - n
+                if pad:
+                    a_place = np.concatenate([a_place, np.repeat(a_place[-1:], pad, axis=0)])
+            with jax.profiler.TraceAnnotation("costream.dispatch", rows=n + pad):
+                a_place = jnp.asarray(a_place)
+                if stacked is not None:
+                    # a_place was built above for this one call: donate its buffer
+                    pending = placed_predict_fused(
+                        stacked, skel, a_place, static, deferred=True,
+                        chunk=self.policy.score_chunk, donate=True,
+                    )
+
+                    def finalize():
+                        return {m: v[:n] for m, v in pending.result().items()}
+
+                else:
+                    # heterogeneous (non-fusable) configs: per-metric loop, computed
+                    # eagerly — the rare path keeps no deferral, only the wrapper type
+                    out = {
+                        m: placed_predict(
+                            self.models[m][0], skel, a_place, static, self.models[m][1]
+                        )[:n]
+                        for m in metrics
+                    }
+
+                    def finalize():
+                        return out
+
+            return self._finish("score", finalize, deferred)
 
         return score
 
@@ -684,20 +711,21 @@ class CostEstimator:
         launched: List[Tuple[jax.Array, int]] = []
         fields = [np.asarray(x) for x in merged]
         for s in range(0, total, step):
-            chunk = JointGraph(*[x[s : s + step] for x in fields])
-            n = int(chunk.op_x.shape[0])
-            chunk = pad_batch(chunk, bucket_size(n))
-            banding = exact_banding_cached(chunk)
-            # the chunk's device copy exists only for this launch: donate it
-            fwd = _jitted_forward_stacked(
-                stacked.cfgs[0].gnn, False, banding, active_lowering(), _can_donate()
-            )
-            raw = fwd(stacked.params, jax.tree_util.tree_map(jnp.asarray, chunk))
+            n = min(step, total - s)
+            with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
+                chunk = pad_batch(JointGraph(*[x[s : s + step] for x in fields]), bucket_size(n))
+                banding = exact_banding_cached(chunk)
+            with jax.profiler.TraceAnnotation("costream.dispatch", rows=bucket_size(n)):
+                # the chunk's device copy exists only for this launch: donate it
+                fwd = _jitted_forward_stacked(
+                    stacked.cfgs[0].gnn, False, banding, active_lowering(), _can_donate()
+                )
+                raw = fwd(stacked.params, jax.tree_util.tree_map(jnp.asarray, chunk))
             launched.append((raw, n))
 
         def finalize() -> List[Dict[str, np.ndarray]]:
             parts = [
-                {m: v[:n] for m, v in _split_votes(np.asarray(raw), stacked).items()}
+                {m: v[:n] for m, v in _split_votes(_fetch(raw), stacked).items()}
                 for raw, n in launched
             ]
             merged_out = {m: np.concatenate([p[m] for p in parts]) for m in metrics}
@@ -732,7 +760,7 @@ class CostEstimator:
             return _maybe_defer(lambda: [], deferred)
         host = []
         for b in batches:
-            g = jax.tree_util.tree_map(np.asarray, self._as_graphs(b))
+            g = jax.tree_util.tree_map(np.asarray, self._host_graphs(b))
             if g.op_x.ndim == 2:  # single graph: promote to a batch of one
                 g = jax.tree_util.tree_map(lambda x: x[None], g)
             host.append(g)
@@ -776,7 +804,8 @@ class CostEstimator:
 
             return _maybe_defer(finalize_fallback, deferred)
         self._before("estimate_many", total_graphs)
-        merged, sizes = merge_graph_batches(host)
+        with jax.profiler.TraceAnnotation("costream.featurize", rows=total_graphs):
+            merged, sizes = merge_graph_batches(host)
         pending = self._merged_forward(merged, sizes, metrics, max_rows, deferred=True)
         return self._finish("estimate_many", pending.result, deferred)
 
@@ -829,19 +858,21 @@ class CostEstimator:
                 raise ValueError("no candidates to score")
             mats.append(a)
             groups.setdefault(keys[i], []).append(i)
-        self._before("score_many", sum(len(a) for a in mats))
+        rows = sum(len(a) for a in mats)
+        self._before("score_many", rows)
 
-        index_of, skels_dev, banding, max_parents = self._merged_group_for(
-            requests, groups
-        )
-        blocks, ids = [], []
-        for key, idxs in groups.items():
-            q, c, _ = requests[idxs[0]]
-            block = build_a_place_batch(q, c, np.concatenate([mats[i] for i in idxs]))
-            blocks.append(block)
-            ids.append(np.full(len(block), index_of[key], dtype=np.int32))
-        skel_id = np.concatenate(ids) if len(ids) > 1 else ids[0]
-        a_place = np.concatenate(blocks) if len(blocks) > 1 else blocks[0]
+        with jax.profiler.TraceAnnotation("costream.featurize", rows=rows):
+            index_of, skels_dev, banding, max_parents = self._merged_group_for(
+                requests, groups
+            )
+            blocks, ids = [], []
+            for key, idxs in groups.items():
+                q, c, _ = requests[idxs[0]]
+                block = build_a_place_batch(q, c, np.concatenate([mats[i] for i in idxs]))
+                blocks.append(block)
+                ids.append(np.full(len(block), index_of[key], dtype=np.int32))
+            skel_id = np.concatenate(ids) if len(ids) > 1 else ids[0]
+            a_place = np.concatenate(blocks) if len(blocks) > 1 else blocks[0]
         pending = self._merged_placements_forward(
             skels_dev, banding, max_parents, skel_id, a_place,
             [len(b) for b in blocks], stacked, metrics, max_rows, deferred=True,
@@ -925,14 +956,16 @@ class CostEstimator:
             n = len(ids)
             pad = bucket_size(n) - n
             if pad:
-                ids = np.concatenate([ids, np.repeat(ids[-1:], pad)])
-                ap = np.concatenate([ap, np.repeat(ap[-1:], pad, axis=0)])
-            raw = fwd(stacked.params, skels_dev, jnp.asarray(ids), jnp.asarray(ap))
+                with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
+                    ids = np.concatenate([ids, np.repeat(ids[-1:], pad)])
+                    ap = np.concatenate([ap, np.repeat(ap[-1:], pad, axis=0)])
+            with jax.profiler.TraceAnnotation("costream.dispatch", rows=n + pad):
+                raw = fwd(stacked.params, skels_dev, jnp.asarray(ids), jnp.asarray(ap))
             launched.append((raw, n))
 
         def finalize() -> List[Dict[str, np.ndarray]]:
             parts = [
-                {m: v[:n] for m, v in _split_votes(np.asarray(raw), stacked).items()}
+                {m: v[:n] for m, v in _split_votes(_fetch(raw), stacked).items()}
                 for raw, n in launched
             ]
             merged_out = {m: np.concatenate([p[m] for p in parts]) for m in metrics}

@@ -57,6 +57,7 @@ Latency engineering (docs/load_harness.md measures all three):
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -158,6 +159,20 @@ class _Request(NamedTuple):
     future: Future
     t_submit: float  # monotonic enqueue time (time-in-queue tracking)
     deadline_s: Optional[float] = None  # answer-by budget from submit time
+    seq: int = 0  # per-service submit number: the ``req`` of its trace spans
+
+
+def _launch_args(reqs: List[_Request]) -> Dict[str, int]:
+    """The lowest and highest request number and the candidate rows of one
+    group, for its ``costream.launch`` span.  Reads shapes only, so it never
+    raises on a malformed request: that one fails alone, inside the launch."""
+    seqs = [r.seq for r in reqs]
+    if reqs[0].kind == "score":
+        shapes = [np.shape(r.payload[2]) for r in reqs]
+    else:
+        shapes = [np.shape(r.payload[0].op_x) for r in reqs]
+    rows = sum(s[0] if s else 0 for s in shapes)
+    return {"first_req": min(seqs), "last_req": max(seqs), "rows": rows}
 
 
 class _LaunchedGroup(NamedTuple):
@@ -165,10 +180,13 @@ class _LaunchedGroup(NamedTuple):
 
     ``finalize`` blocks on the device values and returns ``(answers,
     n_forwards, n_cross)`` — the per-request answers (values or exceptions)
-    plus the work counters recorded at launch."""
+    plus the work counters recorded at launch.  ``drain`` numbers the
+    worker's drain that launched it, shared by its launch and finalize
+    spans."""
 
     reqs: List[_Request]
     finalize: Callable[[], Tuple[List[object], int, int]]
+    drain: int
 
 
 class PlacementService:
@@ -264,6 +282,10 @@ class PlacementService:
         self._known_mixes: "OrderedDict[frozenset, bool]" = OrderedDict()
         self._n_runtime_mixes = 0
         self._queue: "deque[_Request]" = deque()
+        # submit numbers (the spans' ``req``): next() on a count is one C
+        # call, so concurrent submitters never share a number
+        self._seq = itertools.count()
+        self._drain = 0  # the worker's drain number (the spans' ``drain``)
         self._cond = threading.Condition()
         self._stopped = False
         self._thread: Optional[threading.Thread] = None
@@ -535,18 +557,20 @@ class PlacementService:
         the bounded queue is full.  ``deadline_s`` is an answer-by budget
         from submit time, enforced at drain-finalize: a late answer is
         replaced by ``EstimateTimeoutError`` (docs/robustness.md#deadlines)."""
-        metrics = self._resolve_metrics(metrics)
-        a = np.asarray(assignments, dtype=np.int64)
-        skel_key = skeleton_cache_key(query, cluster)
-        # cross-query services group on metrics alone — distinct structures
-        # merge at drain time; the structure key rides along for sub-routing
-        key = ("score", metrics) if self.cross_query else ("score", skel_key, metrics)
-        return self._submit(
-            _Request(
-                "score", key, (query, cluster, a, metrics, skel_key), Future(),
-                time.monotonic(), self._check_deadline(deadline_s),
+        seq = next(self._seq)
+        with jax.profiler.TraceAnnotation("costream.submit", req=seq):
+            metrics = self._resolve_metrics(metrics)
+            a = np.asarray(assignments, dtype=np.int64)
+            skel_key = skeleton_cache_key(query, cluster)
+            # cross-query services group on metrics alone — distinct structures
+            # merge at drain time; the structure key rides along for sub-routing
+            key = ("score", metrics) if self.cross_query else ("score", skel_key, metrics)
+            return self._submit(
+                _Request(
+                    "score", key, (query, cluster, a, metrics, skel_key), Future(),
+                    time.monotonic(), self._check_deadline(deadline_s), seq,
+                )
             )
-        )
 
     def submit_estimate(
         self,
@@ -558,18 +582,20 @@ class PlacementService:
 
         Raises ``ServiceOverloadError`` (or blocks, per ``overflow``) when
         the bounded queue is full.  ``deadline_s`` as in ``submit_score``."""
-        metrics = self._resolve_metrics(metrics)
-        if not isinstance(graphs, JointGraph):
-            graphs = self.estimator._as_graphs(graphs)
-        if graphs.op_x.ndim == 2:  # single graph: promote to a batch of one
-            graphs = jax.tree_util.tree_map(lambda x: np.asarray(x)[None], graphs)
-        key = ("estimate", metrics)
-        return self._submit(
-            _Request(
-                "estimate", key, (graphs, metrics), Future(), time.monotonic(),
-                self._check_deadline(deadline_s),
+        seq = next(self._seq)
+        with jax.profiler.TraceAnnotation("costream.submit", req=seq):
+            metrics = self._resolve_metrics(metrics)
+            if not isinstance(graphs, JointGraph):
+                graphs = self.estimator._as_graphs(graphs)
+            if graphs.op_x.ndim == 2:  # single graph: promote to a batch of one
+                graphs = jax.tree_util.tree_map(lambda x: np.asarray(x)[None], graphs)
+            key = ("estimate", metrics)
+            return self._submit(
+                _Request(
+                    "estimate", key, (graphs, metrics), Future(), time.monotonic(),
+                    self._check_deadline(deadline_s), seq,
+                )
             )
-        )
 
     def score(self, query, cluster, assignments, metrics=None) -> Dict[str, np.ndarray]:
         """Synchronous convenience: submit one score request and wait."""
@@ -595,13 +621,14 @@ class PlacementService:
         try:
             while True:
                 with self._cond:
-                    while (
-                        not self._queue
-                        and not self._stopped
-                        and not pending
-                        and self._pending_swap is None
-                    ):
-                        self._cond.wait()
+                    with jax.profiler.TraceAnnotation("costream.worker.wait"):
+                        while (
+                            not self._queue
+                            and not self._stopped
+                            and not pending
+                            and self._pending_swap is None
+                        ):
+                            self._cond.wait()
                     # the drain boundary: an estimator swap applies here —
                     # groups in `pending` hold the OLD estimator in their
                     # finalize closures and finish on it; everything popped
@@ -631,6 +658,7 @@ class PlacementService:
                     swap[1].set_result(old_est)
                 launched = []
                 if batch:
+                    self._drain += 1
                     groups: Dict[Tuple, List[_Request]] = {}  # dicts keep insertion order
                     for req in batch:
                         groups.setdefault(req.key, []).append(req)
@@ -673,85 +701,105 @@ class PlacementService:
             raise
 
     def _launch_group(self, reqs: List[_Request]) -> _LaunchedGroup:
-        """Host-side half of one group: featurize + dispatch, don't block."""
-        try:
-            if reqs[0].kind == "score":
-                finalize = self._launch_scores(reqs)
-            else:
-                finalize = self._launch_estimates(reqs)
-        except BaseException as e:  # launch failed: the whole group shares the error
-            finalize = (lambda err: lambda: ([err] * len(reqs), 0, 0))(e)
-        return _LaunchedGroup(reqs, finalize)
+        """Host-side half of one group: featurize + dispatch, don't block.
+
+        Traced as ``costream.launch``; its ``path`` is the route taken
+        (``placed``, ``merged``, ``degraded`` or ``estimate``), or ``failed``
+        when the launch itself raised.  Its request numbers and rows are
+        worked out only while a profiler records."""
+        with jax.profiler.TraceAnnotation(
+            "costream.launch", drain=self._drain, n_req=len(reqs)
+        ) as span:
+            try:
+                if reqs[0].kind == "score":
+                    path, finalize = self._launch_scores(reqs)
+                else:
+                    path, finalize = "estimate", self._launch_estimates(reqs)
+            except BaseException as e:  # launch failed: the whole group shares the error
+                path = "failed"
+                finalize = (lambda err: lambda: ([err] * len(reqs), 0, 0))(e)
+            if span.is_enabled():
+                span.set_metadata(path=path, **_launch_args(reqs))
+        return _LaunchedGroup(reqs, finalize, self._drain)
 
     def _finalize_group(self, lg: _LaunchedGroup) -> None:
         """Device-side half: block on results, record work, resolve futures."""
-        try:
-            answers, n_forwards, n_cross = lg.finalize()
-        except BaseException as e:  # deliver, don't kill the worker
-            answers, n_forwards, n_cross = [e] * len(lg.reqs), 0, 0
-        answers = list(answers)
-        # deadlines are judged where the answer materializes: an estimate
-        # that finished after the caller's budget is replaced, not delivered
-        now = time.monotonic()
-        for j, r in enumerate(lg.reqs):
-            if r.deadline_s is not None and (now - r.t_submit) > r.deadline_s:
-                answers[j] = EstimateTimeoutError(
-                    f"{r.kind} answered in {now - r.t_submit:.3f}s, "
-                    f"over its {r.deadline_s:.3f}s deadline"
-                )
-        # count the work before resolving futures, so a caller woken by
-        # result() never observes counters lagging its own answer
-        with self._cond:
-            self.stats.n_forwards += n_forwards
-            self.stats.n_cross_query += n_cross
-            if len(lg.reqs) > 1:
-                self.stats.n_coalesced += len(lg.reqs)
-            for answer in answers:
-                if isinstance(answer, _Degraded):
-                    self.stats.n_degraded += 1
-                    if isinstance(answer.cause, NonFiniteEstimate):
-                        self.stats.n_nonfinite += 1
-                    if answer.cause is not None:
-                        # a real estimator failure behind the fallback; a
-                        # causeless _Degraded is the breaker's own
-                        # short-circuit and must not re-feed it
-                        self._breaker.record_failure()
-                elif isinstance(answer, EstimateTimeoutError):
-                    self.stats.n_timeouts += 1
-                    self._breaker.record_failure()
-                elif isinstance(answer, NonFiniteEstimate):
-                    self.stats.n_nonfinite += 1
-                    self.stats.n_failed += 1
-                    self._breaker.record_failure()
-                elif isinstance(answer, ValueError):
-                    pass  # caller error, says nothing about estimator health
-                elif isinstance(answer, BaseException):
-                    self.stats.n_failed += 1
-                    self._breaker.record_failure()
-                else:
-                    self._breaker.record_success()
-            self.stats.degraded = self._breaker.state != "closed"
-        # a per-request answer may be an exception (bad request, failed
-        # subgroup): metrics-tuple groups span unrelated callers, so one
-        # request's failure must never fail its batchmates
-        for r, answer in zip(lg.reqs, answers):
-            if isinstance(answer, BaseException):
-                r.future.set_exception(answer)
-            else:
-                r.future.set_result(answer)
-        for obs in list(self._observers):
+        with jax.profiler.TraceAnnotation(
+            "costream.finalize", drain=lg.drain, n_req=len(lg.reqs)
+        ):
             try:
-                obs(lg.reqs, answers)
-            except Exception:
-                pass  # observers are best-effort, never worker-fatal
+                answers, n_forwards, n_cross = lg.finalize()
+            except BaseException as e:  # deliver, don't kill the worker
+                answers, n_forwards, n_cross = [e] * len(lg.reqs), 0, 0
+            answers = list(answers)
+            # deadlines are judged where the answer materializes: an estimate
+            # that finished after the caller's budget is replaced, not delivered
+            now = time.monotonic()
+            for j, r in enumerate(lg.reqs):
+                if r.deadline_s is not None and (now - r.t_submit) > r.deadline_s:
+                    answers[j] = EstimateTimeoutError(
+                        f"{r.kind} answered in {now - r.t_submit:.3f}s, "
+                        f"over its {r.deadline_s:.3f}s deadline"
+                    )
+            # count the work before resolving futures, so a caller woken by
+            # result() never observes counters lagging its own answer
+            with self._cond:
+                self.stats.n_forwards += n_forwards
+                self.stats.n_cross_query += n_cross
+                if len(lg.reqs) > 1:
+                    self.stats.n_coalesced += len(lg.reqs)
+                for answer in answers:
+                    if isinstance(answer, _Degraded):
+                        self.stats.n_degraded += 1
+                        if isinstance(answer.cause, NonFiniteEstimate):
+                            self.stats.n_nonfinite += 1
+                        if answer.cause is not None:
+                            # a real estimator failure behind the fallback; a
+                            # causeless _Degraded is the breaker's own
+                            # short-circuit and must not re-feed it
+                            self._breaker.record_failure()
+                    elif isinstance(answer, EstimateTimeoutError):
+                        self.stats.n_timeouts += 1
+                        self._breaker.record_failure()
+                    elif isinstance(answer, NonFiniteEstimate):
+                        self.stats.n_nonfinite += 1
+                        self.stats.n_failed += 1
+                        self._breaker.record_failure()
+                    elif isinstance(answer, ValueError):
+                        pass  # caller error, says nothing about estimator health
+                    elif isinstance(answer, BaseException):
+                        self.stats.n_failed += 1
+                        self._breaker.record_failure()
+                    else:
+                        self._breaker.record_success()
+                self.stats.degraded = self._breaker.state != "closed"
+            # a per-request answer may be an exception (bad request, failed
+            # subgroup): metrics-tuple groups span unrelated callers, so one
+            # request's failure must never fail its batchmates
+            for r, answer in zip(lg.reqs, answers):
+                if isinstance(answer, BaseException):
+                    r.future.set_exception(answer)
+                else:
+                    r.future.set_result(answer)
+            for obs in list(self._observers):
+                try:
+                    obs(lg.reqs, answers)
+                except Exception:
+                    pass  # observers are best-effort, never worker-fatal
 
-    def _launch_scores(self, reqs: List[_Request]) -> Callable:
+    def _launch_scores(self, reqs: List[_Request]) -> Tuple[str, Callable]:
+        """(route taken, finalize) for one score group."""
         metrics = reqs[0].payload[3]
         answers: List[object] = [None] * len(reqs)
         # bad requests fail individually, they never poison the drain
         live = []
         for i, r in enumerate(reqs):
-            if len(r.payload[2]) == 0:
+            if r.payload[2].ndim != 2:
+                answers[i] = ValueError(
+                    f"assignments must be a (candidates, operators) matrix, "
+                    f"got shape {r.payload[2].shape}"
+                )
+            elif len(r.payload[2]) == 0:
                 answers[i] = ValueError("no candidates to score")
             else:
                 live.append(i)
@@ -766,7 +814,7 @@ class PlacementService:
                     answers[i] = self._degraded_answer(q, c, a, ms, cause=None)
                 return answers, 0, 0
 
-            return finalize
+            return "degraded", finalize
 
         distinct = {reqs[i].payload[4] for i in live}
         rows_per_structure = (
@@ -822,7 +870,7 @@ class PlacementService:
                     answers[i] = ans
                 return answers, n_forwards, n_cross
 
-            return finalize
+            return "merged", finalize
 
         # one structure (or merging unsupported / compute-bound / mix not
         # admitted): the placement-specialized per-structure path, candidate
@@ -887,7 +935,7 @@ class PlacementService:
                     off += size
             return answers, n_forwards, 0
 
-        return finalize
+        return "placed", finalize
 
     def _launch_estimates(self, reqs: List[_Request]) -> Callable:
         metrics = reqs[0].payload[1]

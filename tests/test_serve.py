@@ -602,6 +602,37 @@ def test_bad_request_never_fails_its_batchmates():
     assert svc.stats.n_cross_query == 3  # the valid requests still merged
 
 
+@pytest.mark.parametrize("profiled", [False, True])
+def test_malformed_request_fails_alone_and_the_service_goes_on(profiled, tmp_path):
+    """A 0-d assignments array drained with valid requests fails alone, with
+    or without a profiler recording the drain's spans, and the worker keeps
+    answering later requests."""
+    est = CostEstimator(_models(metrics=("latency_p",)))
+    good = _mixed_requests(n=3, cands=4, seed=71)
+    q0, c0, a0 = good[0]
+    svc = PlacementService(est, auto_start=False)
+    futs = [svc.submit_score(q, c, a) for q, c, a in good]
+    bad = svc.submit_score(q0, c0, np.asarray(3))
+    if profiled:
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.start()
+        with pytest.raises(ValueError, match="matrix"):
+            bad.result(timeout=60)
+        got = [f.result(timeout=60) for f in futs]
+    finally:
+        if profiled:
+            jax.profiler.stop_trace()
+    later = svc.submit_score(q0, c0, a0).result(timeout=60)
+    svc.close()
+    for (q, c, a), have in zip(good, got):
+        np.testing.assert_allclose(
+            have["latency_p"], est.score(q, c, a)["latency_p"], rtol=1e-4, atol=1e-5
+        )
+    np.testing.assert_allclose(later["latency_p"], got[0]["latency_p"], rtol=1e-5, atol=1e-6)
+    assert svc.stats.n_failed == 0
+
+
 def test_service_chunks_oversized_groups():
     """A coalesced group larger than max_batch is scored in chunks but still
     answered per request, exactly."""
