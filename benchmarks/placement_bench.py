@@ -19,7 +19,10 @@ Compares four scoring paths on the same candidate set and the same
                 kernel-body win is a TPU measurement.
 
 Also counts graph materializations per path (the fast paths must build each
-candidate graph exactly once across all metrics).  Untrained ensembles are
+candidate graph exactly once across all metrics).  The fused paths build no
+host graph: their one materialization per candidate is the row of the
+int32 host-index matrix handed to the device (``estimator.placed_indices``),
+from which the forward builds the placement adjacency on the device.  Untrained ensembles are
 fine here: scoring throughput does not depend on the weights' values.
 
     PYTHONPATH=src python benchmarks/placement_bench.py [--quick]
@@ -53,16 +56,18 @@ METRICS = ("latency_p", "success", "backpressure")
 
 
 class BuildCounter:
-    """Counts candidate-graph materializations in both build entry points."""
+    """Counts candidate-graph materializations in every build entry point."""
 
     def __init__(self):
         self.single = 0  # build_graph calls (one candidate each)
-        self.batch = 0  # candidates materialized via build_graph_batch
+        self.batch = 0  # candidates materialized via build_a_place_batch
+        self.indices = 0  # candidates handed to the device as host indices
 
     def install(self):
         self._orig_single = graph_mod.build_graph
         self._orig_batch = graph_mod.build_graph_batch
         self._orig_place = graph_mod.build_a_place_batch
+        self._orig_indices = estimator_mod.placed_indices
 
         def counted_single(*a, **kw):
             self.single += 1
@@ -77,6 +82,10 @@ class BuildCounter:
             self.batch += len(np.asarray(assignments))
             return self._orig_place(query, cluster, assignments, *a, **kw)
 
+        def counted_indices(assignments, *a, **kw):
+            self.indices += len(assignments)
+            return self._orig_indices(assignments, *a, **kw)
+
         graph_mod.build_graph = counted_single
         graph_mod.build_graph_batch = counted_batch
         graph_mod.build_a_place_batch = counted_place
@@ -86,6 +95,7 @@ class BuildCounter:
         estimator_mod.build_graph = counted_single
         estimator_mod.build_graph_batch = counted_batch
         estimator_mod.build_a_place_batch = counted_place
+        estimator_mod.placed_indices = counted_indices
         return self
 
     def uninstall(self):
@@ -96,13 +106,14 @@ class BuildCounter:
         estimator_mod.build_graph = self._orig_single
         estimator_mod.build_graph_batch = self._orig_batch
         estimator_mod.build_a_place_batch = self._orig_place
+        estimator_mod.placed_indices = self._orig_indices
 
     @property
     def total(self) -> int:
-        return self.single + self.batch
+        return self.single + self.batch + self.indices
 
     def reset(self):
-        self.single = self.batch = 0
+        self.single = self.batch = self.indices = 0
 
 
 def make_models(hidden: int = 32, n_ensemble: int = 3, use_pallas: bool = False):

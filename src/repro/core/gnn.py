@@ -710,6 +710,18 @@ def _trimmed_layout(static: QueryStatic):
     return tuple(order), _type_runs(order), updates, tuple(levels)
 
 
+def trimmed_columns(static: QueryStatic, slots) -> Tuple[int, ...]:
+    """The operator id behind each row of the trimmed layout.
+
+    ``slots`` is ``graph.slot_index(query)`` (operator id -> padded slot);
+    its inverse maps ``_trimmed_layout(static)``'s slot order to columns of
+    the query's ``(B, n_ops)`` assignment matrix.  A tuple of ints, so it
+    can join a trace key beside ``static``.
+    """
+    op_of = {int(s): op for op, s in enumerate(slots)}
+    return tuple(op_of[s] for s in _trimmed_layout(static)[0])
+
+
 def apply_gnn_placed_stacked(
     params: nn.Params,
     skel: JointGraph,
@@ -744,7 +756,46 @@ def apply_gnn_placed_stacked(
     ``apply_gnn_placed``, with the trimmed type runs as the kernels' slot
     layout and each stage-3 depth level as a static ``row_span`` for
     ``mp_update`` (the depth-major trimmed order makes levels contiguous).
+
+    ``a_place`` is the ``(B, MAX_OPS, MAX_HW)`` one-hot placement batch;
+    ``apply_gnn_placed_stacked_idx`` is the same forward over host indices.
     """
+    idx = jnp.asarray(_trimmed_layout(static)[0])
+    return _placed_stacked(
+        params, skel, a_place, lambda ap: ap[:, idx, :n_hw], static, cfg, n_hw, chunk
+    )
+
+
+def apply_gnn_placed_stacked_idx(
+    params: nn.Params,
+    skel: JointGraph,
+    assign: jax.Array,
+    cols: Tuple[int, ...],
+    static: QueryStatic,
+    cfg: GNNConfig,
+    n_hw: int,
+    chunk: Optional[int] = None,
+) -> jax.Array:
+    """``apply_gnn_placed_stacked`` over the ``(B, n_ops)`` int matrix of
+    each candidate's host per operator (``cols = trimmed_columns(static,
+    slot_index(query))``).  The trimmed one-hot ``a[b, j, h] =
+    (assign[b, cols[j]] == h)`` is built on the device, one panel at a time:
+    the same exact 0/1 values the one-hot entry reads, so the outputs are
+    bit-identical.  Host ids must lie in ``[0, n_hw)``; the caller checks
+    (``graph.check_host_range``)."""
+    cols_idx = jnp.asarray(cols)
+    hosts = jnp.arange(n_hw, dtype=assign.dtype)
+
+    def one_hot(a):
+        return (a[:, cols_idx, None] == hosts).astype(jnp.float32)
+
+    return _placed_stacked(params, skel, assign, one_hot, static, cfg, n_hw, chunk)
+
+
+def _placed_stacked(params, skel, cands, trim, static, cfg, n_hw, chunk):
+    """The body of both stacked placed entries: ``cands`` is the per-candidate
+    input (leading axis B), ``trim`` maps a panel of it to the ``(b, n, n_hw)``
+    trimmed placement adjacency."""
     if chunk is None:
         from repro.serve.policy import active_policy  # lazy: core never pulls serve at import
 
@@ -755,8 +806,7 @@ def apply_gnn_placed_stacked(
     hw_x = skel.hw_x[:n_hw]  # (n_hw, F_hw)
     a_flow = skel.a_flow[idx][:, idx]  # (n, n)
     op_depth = skel.op_depth[idx]  # (n,)
-    a_place = a_place[:, idx, :n_hw]  # (B, n, n_hw)
-    B = a_place.shape[0]
+    B = cands.shape[0]
     plan = StagePlan("exact", levels=levels, updates=updates)
 
     # stage 0 is placement-invariant: once per member, outside the chunk scan
@@ -773,14 +823,16 @@ def apply_gnn_placed_stacked(
             pp, h_ops0, h_hw0, ap, a_flow, op_depth, cfg, ranges=ranges, plan=plan
         )[..., 0]
 
-    fwd = jax.vmap(member_fwd, in_axes=(0, 0, 0, None))
+    vmapped = jax.vmap(member_fwd, in_axes=(0, 0, 0, None))
+
+    def fwd(panel):
+        return vmapped(params, h0_ops, h0_hw, trim(panel))  # (E, b)
+
     if chunk and B > chunk and B % chunk == 0:
-        panels = a_place.reshape(B // chunk, chunk, *a_place.shape[1:])
-        _, outs = jax.lax.scan(
-            lambda carry, ap: (carry, fwd(params, h0_ops, h0_hw, ap)), None, panels
-        )  # (B/chunk, E, chunk)
-        return outs.transpose(1, 0, 2).reshape(outs.shape[1], B)
-    return fwd(params, h0_ops, h0_hw, a_place)
+        panels = cands.reshape(B // chunk, chunk, *cands.shape[1:])
+        _, outs = jax.lax.scan(lambda carry, c: (carry, fwd(c)), None, panels)
+        return outs.transpose(1, 0, 2).reshape(outs.shape[1], B)  # outs: (B/chunk, E, chunk)
+    return fwd(cands)
 
 
 # ---------------------------------------------------------------------------

@@ -218,6 +218,17 @@ def build_a_place_batch(
     return a_place
 
 
+def check_host_range(assignments: np.ndarray, n_hosts: int) -> None:
+    """Raise ``ValueError`` if any host index of ``assignments`` lies outside
+    ``[0, n_hosts)``: one vectorized min and max."""
+    if assignments.size:
+        lo, hi = int(assignments.min()), int(assignments.max())
+        if lo < 0 or hi >= n_hosts:
+            raise ValueError(
+                f"host index outside [0, {n_hosts}): assignments span [{lo}, {hi}]"
+            )
+
+
 def build_graph(
     query: Query,
     cluster: Cluster,

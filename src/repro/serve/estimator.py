@@ -48,10 +48,12 @@ from repro.core.bucketing import BatchBanding, exact_banding_cached
 from repro.core.gnn import (
     apply_gnn_merged,
     apply_gnn_placed,
-    apply_gnn_placed_stacked,
+    apply_gnn_placed_stacked_idx,
+    trimmed_columns,
     validate_merged_parents,
 )
 from repro.core.graph import (
+    MAX_HW,
     JointGraph,
     QueryStatic,
     batch_graphs,
@@ -60,10 +62,12 @@ from repro.core.graph import (
     build_graph,
     build_graph_batch,
     build_graph_skeleton,
+    check_host_range,
     merge_graph_batches,
     pad_batch,
     query_static,
     skeleton_cache_key,
+    slot_index,
 )
 from repro.core.model import CostModelConfig, forward_ensemble
 from repro.kernels import active_lowering
@@ -172,19 +176,19 @@ def _jitted_placed_forward(cfg: CostModelConfig, static: QueryStatic, lowering: 
 def _jitted_placed_forward_stacked(
     gnn,
     static: QueryStatic,
+    cols: Tuple[int, ...],
     n_hw: int,
     chunk: int = 0,
     lowering: str = "ref",
-    donate: bool = False,
 ):
-    # ``chunk`` (the policy's score_chunk) joins the key: the scan structure
-    # it selects is part of the trace, exactly like a shape.  ``donate``
-    # releases ``a_place`` (per-drain, caller-built) — never the skeleton,
-    # which lives in the estimator's LRU across calls.
-    def placed_forward_stacked(p, skel, a_place):
-        return apply_gnn_placed_stacked(p, skel, a_place, static, gnn, n_hw, chunk)
+    # ``cols`` (the trimmed rows' assignment columns) and ``chunk`` (the
+    # policy's score_chunk) join the key: like a shape, each is part of the
+    # trace.  No donation: the int index input has nothing the output could
+    # alias.
+    def placed_forward_stacked(p, skel, assign):
+        return apply_gnn_placed_stacked_idx(p, skel, assign, cols, static, gnn, n_hw, chunk)
 
-    return jax.jit(placed_forward_stacked, donate_argnums=(2,) if donate else ())
+    return jax.jit(placed_forward_stacked)
 
 
 @_policy_lru
@@ -269,6 +273,12 @@ def _maybe_defer(finalize, deferred: bool):
     return DeferredResult(finalize) if deferred else finalize()
 
 
+def _host_bytes(tree) -> int:
+    """Bytes of the host (numpy) leaves of ``tree``: what a dispatch copies
+    to the device (leaves already there copy nothing)."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree) if isinstance(x, np.ndarray))
+
+
 def _fetch(raw) -> np.ndarray:
     """A forward's output on the host: blocks until the device is done."""
     with jax.profiler.TraceAnnotation("costream.fetch"):
@@ -314,24 +324,25 @@ def placed_predict(
 def placed_predict_fused(
     stacked: StackedEnsembles,
     skel: JointGraph,
-    a_place: jax.Array,
+    assign: jax.Array,
     static: QueryStatic,
+    cols: Tuple[int, ...],
     deferred: bool = False,
     chunk: Optional[int] = None,
-    donate: bool = False,
 ) -> Dict[str, np.ndarray]:
     """All metrics' ensembles over one query's candidate placements, fused.
 
-    One jitted ``apply_gnn_placed_stacked`` call evaluates every (metric,
-    member) pair in a single launch per GNN stage, on the trimmed active-slot
-    layout; the raw ``(sum_E, B)`` block is then split back per metric and
-    voted exactly like ``placed_predict`` (the stacked-vs-loop equivalence
-    test pins this to float tolerance).  ``deferred`` dispatches the forward
-    and returns a ``DeferredResult`` whose ``result()`` blocks and splits.
-    ``donate=True`` hands ``a_place``'s device buffer to the launch (freed
-    for the output instead of held alive beside it) — pass it ONLY when the
-    buffer was built for this call and never touched again, as the
-    estimator's drain paths do; a no-op on CPU backends (``_can_donate``).
+    ``assign`` is the ``(B, n_ops)`` int matrix of each candidate's host per
+    operator (ids in ``[0, n_hosts)``), on the host or the device, and
+    ``cols`` its trimmed column order,
+    ``gnn.trimmed_columns(static, slot_index(query))``.  One jitted
+    ``apply_gnn_placed_stacked_idx`` call builds the placement adjacency on
+    the device and evaluates every (metric, member) pair in a single launch
+    per GNN stage, on the trimmed active-slot layout; the raw ``(sum_E, B)``
+    block is then split back per metric and voted exactly like
+    ``placed_predict`` (the stacked-vs-loop equivalence test pins this to
+    float tolerance).  ``deferred`` dispatches the forward and returns a
+    ``DeferredResult`` whose ``result()`` blocks and splits.
     """
     assert not stacked.cfgs[0].traditional_mp, (
         "use the generic path for traditional_mp models"
@@ -340,11 +351,23 @@ def placed_predict_fused(
     if chunk is None:
         chunk = active_policy().score_chunk
     fwd = _jitted_placed_forward_stacked(
-        stacked.cfgs[0].gnn, static, n_hw, chunk, active_lowering(),
-        donate and _can_donate(),
+        stacked.cfgs[0].gnn, static, cols, n_hw, chunk, active_lowering()
     )
-    raw = fwd(stacked.params, skel, a_place)
+    raw = fwd(stacked.params, skel, assign)
     return _maybe_defer(lambda: _split_votes(_fetch(raw), stacked), deferred)
+
+
+def placed_indices(assignments: np.ndarray, n_ops: int, n_hosts: int, rows: int) -> np.ndarray:
+    """The placed engine's per-candidate input: the ``(N, n_ops)`` host
+    indices as one int32 ``(rows, n_ops)`` array, padded by repeating the
+    last row (padding rows are sliced off the answers)."""
+    assert assignments.ndim == 2 and assignments.shape[1] == n_ops, assignments.shape
+    assert n_hosts <= MAX_HW, f"cluster has {n_hosts} hosts > pad {MAX_HW}"
+    n = len(assignments)
+    idx = np.empty((rows, n_ops), dtype=np.int32)
+    idx[:n] = assignments
+    idx[n:] = assignments[-1]
+    return idx
 
 
 # -- the facade -------------------------------------------------------------------
@@ -375,7 +398,7 @@ class CostEstimator:
         self.policy = (policy if policy is not None else resolve_policy()).validate()
         # (query, cluster) pairs kept device-resident
         self.skeleton_cache_size = self.policy.skeleton_cache_size
-        self._skeletons: "OrderedDict[Tuple, Tuple[JointGraph, JointGraph, QueryStatic]]" = (
+        self._skeletons: "OrderedDict[Tuple, Tuple[JointGraph, JointGraph, QueryStatic, Tuple]]" = (
             OrderedDict()
         )
         self._stacked: Dict[Tuple[str, ...], Optional[StackedEnsembles]] = {}
@@ -508,7 +531,7 @@ class CostEstimator:
         rows = int(host.op_x.shape[0]) if host.op_x.ndim == 3 else 1
         self._before("estimate", rows)
         stacked = self._stacked_for(metrics)
-        with jax.profiler.TraceAnnotation("costream.dispatch", rows=rows):
+        with jax.profiler.TraceAnnotation("costream.dispatch", rows=rows, bytes=_host_bytes(host)):
             g = jax.tree_util.tree_map(jnp.asarray, host)
             if stacked is None:  # mixed architectures: per-metric forwards, shared batch
                 lowering = active_lowering()
@@ -542,8 +565,9 @@ class CostEstimator:
 
     def _skeleton_entry(
         self, query, cluster, key: Optional[Tuple] = None
-    ) -> Tuple[JointGraph, JointGraph, QueryStatic]:
-        """Cached (host skeleton, device skeleton, QueryStatic) for one pair.
+    ) -> Tuple[JointGraph, JointGraph, QueryStatic, Tuple[int, ...]]:
+        """Cached (host skeleton, device skeleton, QueryStatic, trimmed
+        assignment columns) for one pair.
 
         The host copy feeds the cross-query merge path (merging concatenates
         on the host before ONE device transfer); the device copy feeds the
@@ -559,16 +583,22 @@ class CostEstimator:
             self._skeletons.move_to_end(key)
             return hit
         host = build_graph_skeleton(query, cluster)
-        entry = (host, jax.tree_util.tree_map(jnp.asarray, host), query_static(query))
+        static = query_static(query)
+        entry = (
+            host,
+            jax.tree_util.tree_map(jnp.asarray, host),
+            static,
+            trimmed_columns(static, slot_index(query)),
+        )
         self._skeletons[key] = entry
         while len(self._skeletons) > self.skeleton_cache_size:
             self._skeletons.popitem(last=False)
         return entry
 
-    def _skeleton_for(self, query, cluster) -> Tuple[JointGraph, QueryStatic]:
-        """Cached (device-resident skeleton, QueryStatic) for one pair."""
-        _, dev, static = self._skeleton_entry(query, cluster)
-        return dev, static
+    def _skeleton_for(self, query, cluster) -> Tuple[JointGraph, QueryStatic, Tuple[int, ...]]:
+        """Cached (device-resident skeleton, QueryStatic, trimmed assignment
+        columns) for one pair."""
+        return self._skeleton_entry(query, cluster)[1:]
 
     def _stacked_for(self, metrics: Tuple[str, ...]) -> Optional[StackedEnsembles]:
         """Fused ensemble stack for ``metrics``, or None if not fusable."""
@@ -611,34 +641,45 @@ class CostEstimator:
 
             return score_generic
 
-        skel, static = self._skeleton_for(query, cluster)
+        skel, static, cols = self._skeleton_for(query, cluster)
         stacked = self._stacked_for(metrics)
+        n_ops, n_hosts = query.n_ops(), cluster.n_nodes()
 
         def score(assignments: np.ndarray) -> Dict[str, np.ndarray]:
             n = len(assignments)
             if n == 0:  # not assert: callers (the service) rely on it under -O
                 raise ValueError("no candidates to score")
             self._before("score", n)
+            rows = bucket_size(n)
             with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
-                a_place = build_a_place_batch(query, cluster, assignments)
-                pad = bucket_size(n) - n
-                if pad:
-                    a_place = np.concatenate([a_place, np.repeat(a_place[-1:], pad, axis=0)])
-            with jax.profiler.TraceAnnotation("costream.dispatch", rows=n + pad):
-                a_place = jnp.asarray(a_place)
+                check_host_range(assignments, n_hosts)
                 if stacked is not None:
-                    # a_place was built above for this one call: donate its buffer
+                    idx = placed_indices(assignments, n_ops, n_hosts, rows)
+                else:
+                    a_place = build_a_place_batch(query, cluster, assignments)
+                    if rows > n:
+                        a_place = np.concatenate(
+                            [a_place, np.repeat(a_place[-1:], rows - n, axis=0)]
+                        )
+            if stacked is not None:
+                # the host array goes straight into the jitted call, whose own
+                # transfer costs a fraction of a separate jnp.asarray on the TPU
+                with jax.profiler.TraceAnnotation("costream.dispatch", rows=rows, bytes=idx.nbytes):
                     pending = placed_predict_fused(
-                        stacked, skel, a_place, static, deferred=True,
-                        chunk=self.policy.score_chunk, donate=True,
+                        stacked, skel, idx, static, cols, deferred=True,
+                        chunk=self.policy.score_chunk,
                     )
 
-                    def finalize():
-                        return {m: v[:n] for m, v in pending.result().items()}
+                def finalize():
+                    return {m: v[:n] for m, v in pending.result().items()}
 
-                else:
-                    # heterogeneous (non-fusable) configs: per-metric loop, computed
-                    # eagerly — the rare path keeps no deferral, only the wrapper type
+            else:
+                # heterogeneous (non-fusable) configs: per-metric loop, computed
+                # eagerly — the rare path keeps no deferral, only the wrapper type
+                with jax.profiler.TraceAnnotation(
+                    "costream.dispatch", rows=rows, bytes=a_place.nbytes
+                ):
+                    a_place = jnp.asarray(a_place)
                     out = {
                         m: placed_predict(
                             self.models[m][0], skel, a_place, static, self.models[m][1]
@@ -646,8 +687,8 @@ class CostEstimator:
                         for m in metrics
                     }
 
-                    def finalize():
-                        return out
+                def finalize():
+                    return out
 
             return self._finish("score", finalize, deferred)
 
@@ -715,7 +756,9 @@ class CostEstimator:
             with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
                 chunk = pad_batch(JointGraph(*[x[s : s + step] for x in fields]), bucket_size(n))
                 banding = exact_banding_cached(chunk)
-            with jax.profiler.TraceAnnotation("costream.dispatch", rows=bucket_size(n)):
+            with jax.profiler.TraceAnnotation(
+                "costream.dispatch", rows=bucket_size(n), bytes=_host_bytes(chunk)
+            ):
                 # the chunk's device copy exists only for this launch: donate it
                 fwd = _jitted_forward_stacked(
                     stacked.cfgs[0].gnn, False, banding, active_lowering(), _can_donate()
@@ -959,7 +1002,9 @@ class CostEstimator:
                 with jax.profiler.TraceAnnotation("costream.featurize", rows=n):
                     ids = np.concatenate([ids, np.repeat(ids[-1:], pad)])
                     ap = np.concatenate([ap, np.repeat(ap[-1:], pad, axis=0)])
-            with jax.profiler.TraceAnnotation("costream.dispatch", rows=n + pad):
+            with jax.profiler.TraceAnnotation(
+                "costream.dispatch", rows=n + pad, bytes=ids.nbytes + ap.nbytes
+            ):
                 raw = fwd(stacked.params, skels_dev, jnp.asarray(ids), jnp.asarray(ap))
             launched.append((raw, n))
 

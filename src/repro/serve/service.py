@@ -69,7 +69,7 @@ import jax
 import numpy as np
 
 from repro.core.bucketing import bucket_size
-from repro.core.graph import JointGraph, skeleton_cache_key
+from repro.core.graph import JointGraph, check_host_range, skeleton_cache_key
 from repro.serve.estimator import CostEstimator, NonFiniteEstimate
 from repro.serve.lifecycle import CircuitBreaker, fallback_scores
 from repro.serve.policy import DispatchPolicy
@@ -794,15 +794,19 @@ class PlacementService:
         # bad requests fail individually, they never poison the drain
         live = []
         for i, r in enumerate(reqs):
-            if r.payload[2].ndim != 2:
+            _, cluster, a, _, _ = r.payload
+            if a.ndim != 2:
                 answers[i] = ValueError(
-                    f"assignments must be a (candidates, operators) matrix, "
-                    f"got shape {r.payload[2].shape}"
+                    f"assignments must be a (candidates, operators) matrix, got shape {a.shape}"
                 )
-            elif len(r.payload[2]) == 0:
+            elif len(a) == 0:
                 answers[i] = ValueError("no candidates to score")
             else:
-                live.append(i)
+                try:
+                    check_host_range(a, cluster.n_nodes())
+                    live.append(i)
+                except ValueError as e:
+                    answers[i] = e
         if live and not self._breaker.allow():
             # circuit open: serve heuristic-placement fallback scores without
             # touching the estimator at all; answers are tagged degraded so

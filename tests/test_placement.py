@@ -14,7 +14,9 @@ from repro.core.graph import (
     build_graph_skeleton,
     query_static,
     skeleton_cache_key,
+    slot_index,
 )
+from repro.core.gnn import trimmed_columns
 from repro.serve.estimator import (
     CostEstimator,
     ensemble_predict,
@@ -250,7 +252,8 @@ def test_stacked_ensembles_match_per_metric_loop():
     models = _tiny_models()
     stacked = stack_metric_models(models)
     assert stacked.sizes == (2, 2, 2)
-    fused = placed_predict_fused(stacked, skel, a_place, static)
+    cols = trimmed_columns(static, slot_index(q))
+    fused = placed_predict_fused(stacked, skel, jnp.asarray(a, dtype=jnp.int32), static, cols)
     for metric, (params, cfg) in models.items():
         ref = placed_predict(params, skel, a_place, static, cfg)
         np.testing.assert_allclose(fused[metric], ref, rtol=1e-5, atol=1e-6, err_msg=metric)

@@ -213,7 +213,7 @@ def test_score_one_skeleton_build_one_stacked_forward(monkeypatch):
     orig_skel = estimator_mod.build_graph_skeleton
     orig_fused = estimator_mod.placed_predict_fused
     orig_placed = estimator_mod.placed_predict
-    orig_apply = estimator_mod.apply_gnn_placed_stacked
+    orig_apply = estimator_mod.apply_gnn_placed_stacked_idx
 
     monkeypatch.setattr(
         estimator_mod,
@@ -232,7 +232,7 @@ def test_score_one_skeleton_build_one_stacked_forward(monkeypatch):
     )
     monkeypatch.setattr(
         estimator_mod,
-        "apply_gnn_placed_stacked",
+        "apply_gnn_placed_stacked_idx",
         lambda *a, **k: (calls.__setitem__("traced", calls["traced"] + 1), orig_apply(*a, **k))[1],
     )
 

@@ -22,8 +22,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.bucketing import bucket_size, exact_banding_cached
 from repro.core.features import OP_FEATURE_DIM
-from repro.core.gnn import GNNConfig, _banded_plan, _clip_ranges
-from repro.core.graph import SLOT_RANGES, batch_graphs, build_graph_skeleton, query_static
+from repro.core.gnn import GNNConfig, _banded_plan, _clip_ranges, trimmed_columns
+from repro.core.graph import (
+    SLOT_RANGES,
+    batch_graphs,
+    build_graph_skeleton,
+    query_static,
+    slot_index,
+)
 from repro.core.model import CostModelConfig, ensemble_loss, init_cost_model
 from repro.dsps.generator import WorkloadGenerator
 from repro.kernels.banked_mlp.kernel import banked_mlp_slotted_pallas
@@ -225,13 +231,15 @@ def test_placed_forward_compiles_for_1024_candidates(one_chip):
 
     (q, c), = _structures(1, seed=5)
     skel = build_graph_skeleton(q, c)
+    static = query_static(q)
     fwd = _jitted_placed_forward_stacked(
-        GNNConfig(hidden=HIDDEN), query_static(q), c.n_nodes(), 256, "ref", True
+        GNNConfig(hidden=HIDDEN), static, trimmed_columns(static, slot_index(q)),
+        c.n_nodes(), 256, "ref",
     )
     fwd.lower(
         _stacked_params(one_chip, GNNConfig(hidden=HIDDEN)),
         _specs(one_chip, skel),
-        _spec(one_chip, (1024, N_OPS, N_HW)),
+        _spec(one_chip, (1024, q.n_ops()), jnp.int32),
     ).compile()
 
 

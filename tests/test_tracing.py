@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import CostModelConfig, GNNConfig, init_cost_model
-from repro.core.graph import batch_graphs, build_graph
+from repro.core.graph import batch_graphs, bucket_size, build_graph
 from repro.dsps import WorkloadGenerator
 from repro.placement import sample_assignment_matrix
 from repro.serve import CostEstimator, PlacementService
@@ -114,6 +114,12 @@ def test_spans_on_their_threads_with_their_arguments(traced):
         assert all(s[3]["rows"] > 0 for s in found)
     fetches = _named(spans, "costream.fetch")
     assert len(fetches) >= 3 and all(_inside(s, finalizes) for s in fetches)
+    # bytes copied to the device: the placed path hands over its int32 index matrix
+    dispatches = _named(spans, "costream.dispatch")
+    assert all(s[3]["bytes"] > 0 for s in dispatches)
+    placed = [s for s in launches if s[3]["path"] == "placed"]
+    assert [s[3]["bytes"] for s in dispatches if _inside(s, placed)] == \
+        [bucket_size(len(_LARGE)) * _STRUCTURES[0][0].n_ops() * 4]
 
 
 @pytest.mark.filterwarnings("ignore::DeprecationWarning")
